@@ -69,6 +69,7 @@ from repro.kernels.registry import (
     export_autotune_cache,
     preload_autotune_cache,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.metrics import (
     DIV_FRAC_OUT,
     PACKED_DIV_FRAC_OUT,
@@ -887,6 +888,7 @@ def append_trajectory(path: str, run_record: dict) -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names, may include 'grid' "

@@ -43,7 +43,6 @@ OP_SOURCES: dict[str, tuple] = {
 #: the full matrix. Directories end with '/' and match by prefix.
 SHARED_SOURCES: tuple = (
     "src/repro/kernels/datapath.py",
-    "src/repro/kernels/common.py",
     "src/repro/kernels/ops.py",
     "src/repro/kernels/ref.py",
     "src/repro/kernels/registry.py",

@@ -40,10 +40,7 @@ import numpy as np
 from .domain import (AbsVal, CaseReport, Finding, TraceCase, from_concrete,
                      join, top)
 
-try:  # jax >= 0.4.34
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Jaxpr, Literal
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 __all__ = ["check_case", "run_matrix", "render_text", "to_json",
            "MatrixResult"]
@@ -133,11 +130,23 @@ _BOOL_OUT = frozenset({
     "reduce_or",
 })
 
-#: call-like primitives we recurse into (pendings pass through unsettled)
-_CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "custom_jvp_call",
-    "custom_vjp_call", "remat", "checkpoint", "custom_vjp_call_jaxpr",
-})
+#: structured control flow carries sub-jaxprs too, but has its own rules
+_CONTROL_PRIMS = frozenset({"while", "scan", "cond"})
+
+
+def _sub_jaxpr(eqn):
+    """The body of a call-like equation (``jit``/``pjit``, custom_jvp/vjp
+    calls, remat, ...), or None. Keyed on the presence of a sub-jaxpr
+    param rather than on primitive names, which differ across JAX
+    releases (``jnp.where`` traces as ``pjit`` on some, ``jit`` on
+    others). Calls are recursed into; pendings pass through unsettled."""
+    if eqn.primitive.name in _CONTROL_PRIMS:
+        return None
+    for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
+        sub = eqn.params.get(key)
+        if isinstance(sub, (ClosedJaxpr, Jaxpr)):
+            return sub
+    return None
 
 
 class _Interp:
@@ -311,6 +320,7 @@ class _Interp:
     # the dispatcher — one branch per primitive family
     def eval_eqn(self, eqn, ins):
         name = eqn.primitive.name
+        sub = _sub_jaxpr(eqn)
         out_aval = eqn.outvars[0].aval
         odt = np.dtype(out_aval.dtype)
 
@@ -324,7 +334,7 @@ class _Interp:
                 return [v.with_shape(shp).norm() if v.is_int else
                         AbsVal(v.dtype, shp, v.lo, v.hi)]
             ins = self._select_clear(eqn, ins)
-        elif name not in _IDENTITY and name not in _CALL_PRIMS:
+        elif name not in _IDENTITY and sub is None:
             # any non-shape consumption of a deferred underflow reports it
             # (calls pass pendings through — the select may live inside)
             ins = [self._settle(v) for v in ins]
@@ -469,8 +479,8 @@ class _Interp:
             return self._scan(eqn, ins)
         if name == "cond":
             return self._cond(eqn, ins)
-        if name in _CALL_PRIMS:
-            return self._call(eqn, ins)
+        if sub is not None:
+            return self._call(eqn, sub, ins)
         self.note_unknown(name)
         return self._top_out(eqn)
 
@@ -712,21 +722,15 @@ class _Interp:
         return [_refine(val, p["lo"], p["hi"], p["bits"])]
 
     # --------------------------------------------------------- control flow --
-    def _call(self, eqn, ins):
-        for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
-            sub = eqn.params.get(key)
-            if isinstance(sub, (ClosedJaxpr, Jaxpr)):
-                jx = sub.jaxpr if isinstance(sub, ClosedJaxpr) else sub
-                # bind inner invars to the outer atoms so pending-underflow
-                # keys and select predicates match across the call boundary
-                for iv, outer in zip(jx.invars, eqn.invars):
-                    self._alias[iv] = outer
-            if isinstance(sub, ClosedJaxpr):
-                return self.eval_closed(sub, ins)
-            if isinstance(sub, Jaxpr):
-                return self.eval_jaxpr(sub, [], ins)
-        self.note_unknown(eqn.primitive.name)
-        return self._top_out(eqn)
+    def _call(self, eqn, sub, ins):
+        jx = sub.jaxpr if isinstance(sub, ClosedJaxpr) else sub
+        # bind inner invars to the outer atoms so pending-underflow keys
+        # and select predicates match across the call boundary
+        for iv, outer in zip(jx.invars, eqn.invars):
+            self._alias[iv] = outer
+        if isinstance(sub, ClosedJaxpr):
+            return self.eval_closed(sub, ins)
+        return self.eval_jaxpr(sub, [], ins)
 
     def _cond(self, eqn, ins):
         branches = eqn.params["branches"]

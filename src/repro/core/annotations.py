@@ -28,10 +28,8 @@ from __future__ import annotations
 
 from contextlib import contextmanager
 
-try:  # jax >= 0.4.34 moved Primitive to jax.extend
-    from jax.extend.core import Primitive
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import Primitive
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
 
 __all__ = [
     "range_contract_p",
@@ -46,12 +44,8 @@ _ACTIVE = False
 range_contract_p = Primitive("simdive_range_contract")
 range_contract_p.def_impl(lambda x, **_: x)
 range_contract_p.def_abstract_eval(lambda x, **_: x)
-try:  # identity lowering: annotated code stays jittable if a trace escapes
-    from jax.interpreters import mlir
-
-    mlir.register_lowering(range_contract_p, lambda ctx, x, **_: [x])
-except Exception:  # pragma: no cover - lowering registration is best-effort
-    pass
+# identity lowering: annotated code stays jittable if a trace escapes
+mlir.register_lowering(range_contract_p, lambda ctx, x, **_: [x])
 
 
 def tracing_active() -> bool:

@@ -251,9 +251,10 @@ def _antilog_floor(ls: jax.Array, width: int, round_out: bool = False,
     shl = jnp.where(big, I - fF, jnp.asarray(0, dt))
     shr = jnp.where(big, jnp.asarray(0, dt), fF - I)
     if round_out:
+        # 1 << (shr-1), and 0 when shr == 0 (nothing is truncated); no
+        # unsigned max — Mosaic cannot lower one on uint32 lanes
         one = jnp.asarray(1, dt)
-        half = one << (jnp.maximum(shr, one) - one)      # 1 << (shr-1)
-        mant = mant + jnp.where(shr > jnp.asarray(0, dt), half, jnp.asarray(0, dt))
+        mant = mant + ((one << shr) >> one)
     out = (mant << shl) >> shr
     # output-bus saturation: a corrected estimate can overshoot 2^(2*width)
     # even when the true product fits — the paper's §2 "overflow cases" in
@@ -279,10 +280,11 @@ def mitchell_antilog_mul(l1: jax.Array, l2: jax.Array, width: int,
     if corr is not None:
         # correction is a signed fixed-point value at F-bit resolution,
         # added in the same "ternary add" as the fraction sum (paper §3.3).
-        ls = jnp.clip(
-            ls.astype(_signed(dt)) + corr.astype(_signed(dt)),
-            0, None,
-        ).astype(dt)
+        sdt = _signed(dt)
+        # typed zero: a Python 0 is a weak int64 under x64, which a Pallas
+        # TPU kernel body cannot convert
+        ls = jnp.maximum(ls.astype(sdt) + corr.astype(sdt),
+                         jnp.asarray(0, sdt)).astype(dt)
     return _antilog_floor(ls, width, round_out=round_out, fast=fast)
 
 
@@ -347,13 +349,14 @@ def mitchell_antilog_div(l1: jax.Array, l2: jax.Array, width: int,
     Xs = ls & ((1 << F) - 1)
     mant = (Xs + (1 << F)).astype(dt)          # 1.Xs, always positive
     sh = I + jnp.asarray(frac_out - F, sdt)    # total shift of the mantissa
+    zero = jnp.asarray(0, sdt)
     nbits = jnp.asarray(63 if dt == jnp.uint64 else 31, sdt)
-    pos = jnp.clip(sh, 0, nbits).astype(dt)
-    negsh = jnp.clip(-sh, 0, nbits).astype(dt)
+    pos = jnp.clip(sh, zero, nbits).astype(dt)
+    negsh = jnp.clip(-sh, zero, nbits).astype(dt)
     if round_out:
+        # 1 << (negsh-1) when sh < 0, else 0 (negsh == 0 there)
         one = jnp.asarray(1, dt)
-        half = one << (jnp.maximum(negsh, one) - one)    # 1 << (negsh-1)
-        mant = mant + jnp.where(sh < 0, half, jnp.asarray(0, dt))
+        mant = mant + ((one << negsh) >> one)
     return jnp.where(sh >= 0, mant << pos, mant >> negsh)
 
 

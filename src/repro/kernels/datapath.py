@@ -19,7 +19,7 @@ Stage map (FPGA block -> function):
     sub-word lane wiring            lane_expand / lane_repack
     whole SISD unit (Fig. 2b)       lane_op            (composes the above)
 
-Every function is plain traceable jnp on values already in registers/VMEM —
+Every stage is plain traceable jnp on values already in registers/VMEM —
 no jit, no pallas_call, no host logic — so identical code runs inside a
 compiled Pallas kernel body, under the Pallas interpreter, and as the
 bit-exact reference oracle. The underlying integer primitives come from
@@ -32,8 +32,9 @@ from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.error_lut import region_index, table_for
+from repro.core.error_lut import build_table, region_index
 from repro.core.fastpath import fastpath_enabled
 from repro.faults.inject import apply_lane_faults, faults_enabled
 from repro.core.mitchell import (
@@ -52,6 +53,7 @@ __all__ = [
     "region_corr",
     "split_tables",
     "op_table",
+    "op_table_host",
     "antilog_mul",
     "antilog_div",
     "log_mul",
@@ -61,7 +63,8 @@ __all__ = [
     "lane_expand",
     "lane_repack",
     "lane_op",
-    "tpu_compiler_params",
+    "to_float32",
+    "I0",
 ]
 
 
@@ -132,24 +135,50 @@ def _static_zero_table(tab, in_kernel: bool) -> bool:
             and not isinstance(tab, jax.core.Tracer) and not tab.any())
 
 
+def _select_lookup(idx: jnp.ndarray, tab) -> jnp.ndarray:
+    """``tab[idx]`` as a binary select tree over the table's constants.
+
+    Kernel bodies get the coefficient table as a trace-time constant (it
+    is fixed by the op's spec), so the lookup needs no gather and no
+    (..., T) one-hot intermediate: one select per tree node, with every
+    leaf a scalar. Subtrees whose entries are all equal collapse to their
+    constant, so sparse tables cost less.
+    """
+    vals = np.asarray(tab).astype(np.int32)
+    T = vals.shape[0]
+    nbits = max(T - 1, 0).bit_length()
+    bits = [(idx & np.int32(1 << b)) != 0 for b in range(nbits)]
+
+    def node(lo: int, size: int, b: int):
+        seg = vals[lo:lo + size]
+        if (seg == seg[0]).all():
+            return seg[0]
+        half = size // 2
+        return jnp.where(bits[b], node(lo + half, half, b - 1),
+                         node(lo, half, b - 1))
+
+    return jnp.broadcast_to(node(0, 1 << nbits, nbits - 1), idx.shape)
+
+
 def corr_lookup(idx: jnp.ndarray, tab: jnp.ndarray, width: int, *,
                 in_kernel: bool = False) -> jnp.ndarray:
     """Gather ``tab[idx]`` (tab: (T,) int32, idx: any shape int32) -> int32.
 
-    A dynamic gather is awkward on the TPU VPU, so inside kernel bodies
-    (``in_kernel=True``) the widths <= 16 lookup is expressed as a one-hot
-    dot product — 64 MACs/element that land on the MXU. Exact because
-    |coeff| < 2^14 << 2^24 (f32 integer-exact range). Outside kernels (the
-    ref/CPU oracles) a plain gather is both exact and far cheaper, so the
-    fast path uses it; the width-32 path always gathers (Mosaic supports
-    small VMEM table gathers) and is exercised in interpret mode.
+    Inside kernel bodies (``in_kernel=True``) the table is a trace-time
+    constant and the lookup is a select tree (:func:`_select_lookup`):
+    Mosaic has no vector gather, and a one-hot product would hold a
+    (..., T) tile in VMEM. The faithful oracle (fast paths off) keeps the
+    one-hot dot product — exact, since |coeff| < 2^14 << 2^24 (the f32
+    integer-exact range). Otherwise a plain gather is exact and cheapest.
     """
     T = tab.shape[0]
     if _static_zero_table(tab, in_kernel):
         # the gather of a constant zero table is not XLA-foldable the way
         # the one-hot product is, so fold it here
         return jnp.zeros(idx.shape, jnp.int32)
-    if width <= 16 and (in_kernel or not fastpath_enabled()):
+    if in_kernel:
+        return _select_lookup(idx, tab)
+    if width <= 16 and not fastpath_enabled():
         onehot = (idx[..., None] == jnp.arange(T, dtype=jnp.int32)).astype(
             jnp.float32
         )
@@ -189,15 +218,22 @@ def split_tables(tab: jnp.ndarray, index_bits: int, op: str):
     return tab[:T], tab[T:]
 
 
+def op_table_host(op: str, width: int, coeff_bits: int,
+                  index_bits: int = 3) -> np.ndarray:
+    """The coefficient table an op needs ('mixed' -> [mul|div]) as host
+    numpy: kernel bodies bake it in as trace-time constants."""
+    if op == "mixed":
+        return np.concatenate([
+            build_table("mul", width, coeff_bits, index_bits),
+            build_table("div", width, coeff_bits, index_bits),
+        ])
+    return build_table(op, width, coeff_bits, index_bits)
+
+
 def op_table(op: str, width: int, coeff_bits: int,
              index_bits: int = 3) -> jnp.ndarray:
     """Materialize the coefficient table an op needs ('mixed' -> [mul|div])."""
-    if op == "mixed":
-        return jnp.concatenate([
-            table_for("mul", width, coeff_bits, index_bits),
-            table_for("div", width, coeff_bits, index_bits),
-        ])
-    return table_for(op, width, coeff_bits, index_bits)
+    return jnp.asarray(op_table_host(op, width, coeff_bits, index_bits))
 
 
 # -------------------------------------------------------------- anti-log --
@@ -295,9 +331,14 @@ def sign_split(x: jnp.ndarray, width: int):
     back on at the output, like every sign-magnitude log multiplier.
     """
     sign = jnp.where(x < 0, jnp.int32(-1), jnp.int32(1))
-    mag = jnp.abs(x).astype(jnp.uint32)
-    mag = jnp.minimum(mag, jnp.uint32((1 << width) - 1))
-    return mag, sign
+    lim = (1 << width) - 1
+    if lim <= jnp.iinfo(x.dtype).max:
+        # clamp in the signed domain before abs: no unsigned min (Mosaic
+        # cannot lower one), and the most negative int still maps to the
+        # lane maximum. A lane wider than the carrier needs no clamp.
+        lim = jnp.asarray(lim, x.dtype)
+        x = jnp.clip(x, -lim, lim)
+    return jnp.abs(x).astype(jnp.uint32), sign
 
 
 def sign_join(mag: jnp.ndarray, sign: jnp.ndarray) -> jnp.ndarray:
@@ -368,6 +409,8 @@ def lane_op(a: jnp.ndarray, b: jnp.ndarray, tab: jnp.ndarray, *, width: int,
     dt = work_dtype(width)
     a = a.astype(dt)
     b = b.astype(dt)
+    if in_kernel:
+        tab = np.asarray(tab)    # a trace-time constant (see corr_lookup)
     la = lod_log(a, width, in_kernel=in_kernel)
     lb = lod_log(b, width, in_kernel=in_kernel)
     nz = (a != 0) & (b != 0)
@@ -411,12 +454,24 @@ def lane_op(a: jnp.ndarray, b: jnp.ndarray, tab: jnp.ndarray, *, width: int,
     return jnp.where(mode != 0, p, q)
 
 
-# ------------------------------------------------------------ host compat --
-def tpu_compiler_params(**kwargs):
-    """jax-version-portable ``pltpu.CompilerParams`` (renamed across jax
-    releases: TPUCompilerParams <= 0.4.x, CompilerParams afterwards)."""
-    from jax.experimental.pallas import tpu as pltpu
+# ---------------------------------------------------------- kernel wiring --
+#: an int32 block index: under x64 a Python 0 traces as int64, which
+#: Mosaic refuses in an index map
+I0 = np.int32(0)
 
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+
+# ---------------------------------------------------------- conversions --
+def to_float32(u: jnp.ndarray) -> jnp.ndarray:
+    """Unsigned lanes -> float32, rounded once like ``u.astype(float32)``.
+
+    Mosaic has no unsigned -> float cast, so a uint32 value is split into
+    16-bit halves that convert exactly through int32; ``hi * 2^16`` is
+    exact too, and the one rounding is the final add. uint64 lanes
+    (width 32, never inside a TPU kernel) convert directly.
+    """
+    if u.dtype != jnp.uint32:
+        return u.astype(jnp.float32)
+    s16 = jnp.uint32(16)
+    hi = (u >> s16).astype(jnp.int32).astype(jnp.float32)
+    lo = (u & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo

@@ -22,7 +22,7 @@ Two schedules (RAPID, arXiv:2206.13970 — same datapath, new schedule):
 * ``pipeline_depth=0`` — grid (BH, nq, nk) with the k axis innermost
   ("arbitrary"); Pallas streams k/v tiles via BlockSpecs and the online
   max/denominator/accumulator live in VMEM scratch across the nk steps.
-* ``pipeline_depth=D>=1`` — grid (BH, nq); k/v stay in ANY/HBM space and the
+* ``pipeline_depth=D>=1`` — grid (BH, nq); k/v stay in HBM and the
   kernel drives its own double-buffered DMA: D VMEM slots per operand, chunk
   c+D-1's copy-in starts while chunk c computes. D=1 degenerates to a serial
   copy-then-compute loop. Every depth is bit-identical to the depth-0 grid
@@ -94,12 +94,15 @@ def softmax_div(acc, l, tab, *, width: int, index_bits: int = 3,
     # Found by repro.analysis.widthcheck (lane-domain, w32).
     lim = jnp.float32(lane_max_float(width))
     dt = work_dtype(width)
-    qn = jnp.clip(jnp.round(num * sc), 0.0, lim).astype(dt)
-    qd = jnp.clip(jnp.round(den * sc), 1.0, lim).astype(dt)
+    # float -> signed -> unsigned: Mosaic has no float -> unsigned cast,
+    # and every clipped value fits the signed carrier
+    sdt = jnp.int32 if dt == jnp.uint32 else jnp.int64
+    qn = jnp.clip(jnp.round(num * sc), 0.0, lim).astype(sdt).astype(dt)
+    qd = jnp.clip(jnp.round(den * sc), 1.0, lim).astype(sdt).astype(dt)
     quot = dp.lane_op(qn, jnp.broadcast_to(qd, qn.shape), tab, width=width,
                       index_bits=index_bits, op="div", frac_out=frac_out,
                       round_out=round_out, in_kernel=in_kernel)
-    out = quot.astype(jnp.float32) * jnp.float32(2.0 ** -frac_out)
+    out = dp.to_float32(quot) * jnp.float32(2.0 ** -frac_out)
     return jnp.where(acc < 0, -out, out)
 
 
@@ -142,7 +145,7 @@ def _finalize_tile(acc, l, tab, *, approx_div: bool, spec: SimdiveSpec,
     return out.astype(out_dtype)
 
 
-def _kernel(q_ref, k_ref, v_ref, tab_ref, o_ref, m_sc, l_sc, acc_sc, *,
+def _kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *, tab,
             nk: int, kc: int, causal: bool, window: int, scale: float,
             kv_len: int, q_offset: int, approx_div: bool,
             spec: SimdiveSpec, frac_out: int):
@@ -167,12 +170,12 @@ def _kernel(q_ref, k_ref, v_ref, tab_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
     @pl.when(kj == nk - 1)
     def _fin():
-        o_ref[0] = _finalize_tile(acc_sc[...], l_sc[...], tab_ref[...],
+        o_ref[0] = _finalize_tile(acc_sc[...], l_sc[...], tab,
                                   approx_div=approx_div, spec=spec,
                                   frac_out=frac_out, out_dtype=o_ref.dtype)
 
 
-def _kernel_pipelined(q_ref, k_hbm, v_hbm, tab_ref, o_ref, *,
+def _kernel_pipelined(q_ref, k_hbm, v_hbm, o_ref, *, tab,
                       nk: int, kc: int, depth: int, causal: bool,
                       window: int, scale: float, kv_len: int, q_offset: int,
                       approx_div: bool, spec: SimdiveSpec, frac_out: int,
@@ -190,12 +193,15 @@ def _kernel_pipelined(q_ref, k_hbm, v_hbm, tab_ref, o_ref, *,
 
     def body(k_sc, v_sc, k_sem, v_sem):
         def dma(c, slot):
+            # whole (kc, dh) rows, int32 offsets: no lane-axis slice (a
+            # d_head below 128 is not lane-aligned) and no i64 index
+            c, slot = jnp.int32(c), jnp.int32(slot)
             return (
                 pltpu.make_async_copy(
-                    k_hbm.at[b, pl.ds(c * kc, kc), :], k_sc.at[slot],
+                    k_hbm.at[b, pl.ds(c * kc, kc)], k_sc.at[slot],
                     k_sem.at[slot]),
                 pltpu.make_async_copy(
-                    v_hbm.at[b, pl.ds(c * kc, kc), :], v_sc.at[slot],
+                    v_hbm.at[b, pl.ds(c * kc, kc)], v_sc.at[slot],
                     v_sem.at[slot]),
             )
 
@@ -209,29 +215,30 @@ def _kernel_pipelined(q_ref, k_hbm, v_hbm, tab_ref, o_ref, *,
 
             @pl.when(nxt < nk)
             def _prefetch():
-                for cp in dma(nxt, jax.lax.rem(nxt, depth)):
+                for cp in dma(nxt, jax.lax.rem(nxt, jnp.int32(depth))):
                     cp.start()
 
-            slot = jax.lax.rem(c, depth)
+            slot = jax.lax.rem(c, jnp.int32(depth))
             for cp in dma(c, slot):
                 cp.wait()
             return _online_step(
-                q, k_sc[slot], v_sc[slot], m, l, acc,
+                q, k_sc[slot][:, :dh], v_sc[slot][:, :dh], m, l, acc,
                 qi * qc + q_offset, c * kc,
                 causal=causal, window=window, kv_len=kv_len, scale=scale)
 
         m0 = jnp.full((qc,), -jnp.inf, jnp.float32)
         carry = (m0, jnp.zeros((qc,), jnp.float32),
                  jnp.zeros((qc, dh), jnp.float32))
-        m, l, acc = jax.lax.fori_loop(0, nk, step, carry)
-        o_ref[0] = _finalize_tile(acc, l, tab_ref[...],
+        m, l, acc = jax.lax.fori_loop(dp.I0, jnp.int32(nk), step, carry)
+        o_ref[0] = _finalize_tile(acc, l, tab,
                                   approx_div=approx_div, spec=spec,
                                   frac_out=frac_out, out_dtype=o_ref.dtype)
 
+    dkv = k_hbm.shape[2]                  # d_head padded to the lane width
     pl.run_scoped(
         body,
-        k_sc=pltpu.VMEM((depth, kc, dh), kv_dtype),
-        v_sc=pltpu.VMEM((depth, kc, dh), kv_dtype),
+        k_sc=pltpu.VMEM((depth, kc, dkv), kv_dtype),
+        v_sc=pltpu.VMEM((depth, kc, dkv), kv_dtype),
         k_sem=pltpu.SemaphoreType.DMA((depth,)),
         v_sem=pltpu.SemaphoreType.DMA((depth,)),
     )
@@ -267,49 +274,55 @@ def flash_attention_pallas(q, k, v, *, spec: SimdiveSpec = DEFAULT_DIV_SPEC,
     kc = min(kv_chunk, Skv)
     assert Sq % qc == 0 and Skv % kc == 0, "pad outside"
     nq, nk = Sq // qc, Skv // kc
-    tab = _div_table(spec.width, spec.coeff_bits, spec.index_bits)
-    common = dict(nk=nk, kc=kc, causal=causal, window=window,
+    tab = dp.op_table_host("div", spec.width, spec.coeff_bits,
+                           spec.index_bits)
+    common = dict(tab=tab, nk=nk, kc=kc, causal=causal, window=window,
                   scale=dh ** -0.5, kv_len=kv_len, q_offset=q_offset,
                   approx_div=approx_div, spec=spec, frac_out=frac_out)
     if pipeline_depth:
+        # the ring's DMA copies whole HBM rows, and Mosaic slices only
+        # lane-aligned rows: k/v cross HBM zero-padded to the lane width,
+        # and the kernel cuts each VMEM tile back to d_head
+        dkv = dh + (-dh) % 128
+        if dkv != dh:
+            pad = ((0, 0), (0, 0), (0, dkv - dh))
+            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
         kern = functools.partial(_kernel_pipelined, depth=int(pipeline_depth),
                                  kv_dtype=k.dtype, **common)
         return pl.pallas_call(
             kern,
             grid=(BH, nq),
             in_specs=[
-                pl.BlockSpec((1, qc, dh), lambda b, i: (b, i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec((tab.shape[0],), lambda b, i: (0,)),
+                pl.BlockSpec((1, qc, dh), lambda b, i: (b, i, dp.I0)),
+                pl.BlockSpec(memory_space=pltpu.HBM),
+                pl.BlockSpec(memory_space=pltpu.HBM),
             ],
-            out_specs=pl.BlockSpec((1, qc, dh), lambda b, i: (b, i, 0)),
+            out_specs=pl.BlockSpec((1, qc, dh), lambda b, i: (b, i, dp.I0)),
             out_shape=jax.ShapeDtypeStruct((BH, Sq, dh), q.dtype),
-            compiler_params=dp.tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
-        )(q, k, v, tab)
+        )(q, k, v)
     kern = functools.partial(_kernel, **common)
     return pl.pallas_call(
         kern,
         grid=(BH, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, qc, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, kc, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, kc, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((tab.shape[0],), lambda b, i, j: (0,)),
+            pl.BlockSpec((1, qc, dh), lambda b, i, j: (b, i, dp.I0)),
+            pl.BlockSpec((1, kc, dh), lambda b, i, j: (b, j, dp.I0)),
+            pl.BlockSpec((1, kc, dh), lambda b, i, j: (b, j, dp.I0)),
         ],
-        out_specs=pl.BlockSpec((1, qc, dh), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, qc, dh), lambda b, i, j: (b, i, dp.I0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((qc,), jnp.float32),
             pltpu.VMEM((qc,), jnp.float32),
             pltpu.VMEM((qc, dh), jnp.float32),
         ],
-        compiler_params=dp.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, tab)
+    )(q, k, v)
 
 
 @functools.partial(

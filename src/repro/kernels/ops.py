@@ -412,7 +412,7 @@ register_op(
     ref=_elemwise_ref,
     pallas=_elemwise_pallas,
     default_block=ELEMWISE_BLOCK,
-    block_candidates=((128, 256), (256, 512), (512, 512)),
+    block_candidates=((128, 256), (256, 512)),
     analysis=_elemwise_analysis,
 )
 register_op(
@@ -420,7 +420,7 @@ register_op(
     ref=_packed_ref,
     pallas=_packed_pallas,
     default_block=PACKED_BLOCK,
-    block_candidates=((64, 128), (128, 256), (256, 256)),
+    block_candidates=((64, 128), (128, 128), (64, 256)),
     analysis=_packed_analysis,
 )
 # matmul blocks carry the k_unroll autotune axis as a 4th component and the

@@ -31,7 +31,7 @@ from . import datapath as dp
 
 __all__ = ["packed_pallas", "packed_word_op"]
 
-DEFAULT_BLOCK = (128, 256)
+DEFAULT_BLOCK = (64, 128)
 
 
 def packed_word_op(aw, bw, tab, mode=None, *, spec: SimdiveSpec, op: str,
@@ -59,10 +59,10 @@ def packed_word_op(aw, bw, tab, mode=None, *, spec: SimdiveSpec, op: str,
     return dp.lane_repack(outs, 2 * width)
 
 
-def _kernel(a_ref, b_ref, tab_ref, mode_ref, o_ref, *, spec: SimdiveSpec,
+def _kernel(a_ref, b_ref, mode_ref, o_ref, *, tab, spec: SimdiveSpec,
             op: str, frac_out: int):
     mode = mode_ref[...] if op == "mixed" else None
-    o_ref[...] = packed_word_op(a_ref[...], b_ref[...], tab_ref[...], mode,
+    o_ref[...] = packed_word_op(a_ref[...], b_ref[...], tab, mode,
                                 spec=spec, op=op, frac_out=frac_out)
 
 
@@ -85,20 +85,20 @@ def packed_pallas(aw, bw, spec: SimdiveSpec, op: str = "mul", mode=None,
     bm, bn = min(block[0], M), min(block[1], Nw)
     assert M % bm == 0 and Nw % bn == 0
     grid = (M // bm, Nw // bn)
-    tab = dp.op_table(op, spec.width, spec.coeff_bits, spec.index_bits)
+    tab = dp.op_table_host(op, spec.width, spec.coeff_bits, spec.index_bits)
     if mode is None:
         mode = jnp.zeros_like(aw)
-    kern = functools.partial(_kernel, spec=spec, op=op, frac_out=frac_out)
+    kern = functools.partial(_kernel, tab=tab, spec=spec, op=op,
+                             frac_out=frac_out)
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((tab.shape[0],), lambda i, j: (0,)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((bm, 2 * bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, 2 * Nw), jnp.uint32),
         interpret=interpret,
-    )(aw, bw, tab, mode)
+    )(aw, bw, mode)

@@ -3,33 +3,17 @@
 A function (not a module constant) so importing this module never touches
 jax device state — the dry-run driver sets XLA_FLAGS *before* any jax
 import, then calls this.
-
-Compat: ``jax.sharding.AxisType`` (explicit/auto axis typing) only exists
-on newer jax. Where it is absent, :func:`_make_mesh` falls back to
-positional ``Mesh(devices, axis_names)`` construction, which carries the
-same default-auto semantics on those versions.
 """
 from __future__ import annotations
 
-import math
-
-import numpy as np
-
 import jax
-
-try:  # jax >= 0.5-era axis typing
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every axis is implicitly 'auto'
-    AxisType = None
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
-def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    devices = np.asarray(jax.devices()[: math.prod(shape)]).reshape(shape)
-    return Mesh(devices, axes)
+def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
+               devices=None) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,9 +22,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_host_mesh(model: int | None = None):
-    """Small mesh over whatever devices exist (tests / local runs)."""
-    n = len(jax.devices())
+def make_host_mesh(model: int | None = None, devices=None):
+    """Small ("data", "model") mesh over ``devices`` (default: every
+    device of the host) — tests / local runs."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
     model = model or 1
     assert n % model == 0
-    return _make_mesh((n // model, model), ("data", "model"))
+    return _make_mesh((n // model, model), ("data", "model"), devices)

@@ -41,6 +41,8 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.approx import ApproxConfig, serving_segments
+from repro.kernels.registry import resolve_backend
+from repro.launch.compile_cache import use_compile_cache
 from repro.metrics.timing import time_callable
 from repro.models import build
 from repro.models.layers import quantize_weight
@@ -190,7 +192,7 @@ class ResolvedOp:
     width: int
     coeff_bits: int
     index_bits: int
-    backend: str
+    backend: str                 # resolved: 'ref' | 'pallas-interpret' | ...
     frac_out: int | None
     source: str                  # 'policy' entry or the config's own knobs
 
@@ -206,7 +208,8 @@ def resolve_serving_plan(cfg) -> tuple[ResolvedOp, ...]:
     """Resolve every layer's per-op dispatch config at load time.
 
     One row per (policy-resolved layer segment, logical op): the widths /
-    coeff_bits / index_bits / backend the registry will actually serve,
+    coeff_bits / index_bits / backend the registry will actually serve on
+    this host (``auto``/``pallas`` resolved to a concrete lowering),
     including the attention divider's ``frac_out``. Exact-mode configs
     yield an empty plan (nothing approximate dispatches).
     """
@@ -227,9 +230,28 @@ def resolve_serving_plan(cfg) -> tuple[ResolvedOp, ...]:
             rows.append(ResolvedOp(
                 op=op, layer_lo=lo, layer_hi=hi, width=spec.width,
                 coeff_bits=spec.coeff_bits, index_bits=spec.index_bits,
-                backend=backend, frac_out=frac,
+                backend=resolve_backend(backend), frac_out=frac,
                 source="policy" if entry is not None else "config"))
     return tuple(rows)
+
+
+def serving_config(cfg, mode: str, *, emulate: bool = False, policy=None,
+                   backend: str = "auto"):
+    """``cfg`` carrying the serving-time approximation for ``mode``.
+
+    Big-model serving default: divider-softmax only (linears stay MXU
+    int8); ``emulate`` opts into bit-exact approximate linears. A policy
+    implies approximate serving. The backend is ``auto`` — the compiled
+    Pallas kernels on a TPU, the bit-exact reference elsewhere — and a
+    policy entry that names a backend still pins it for its op.
+    """
+    if policy is not None and mode == "exact":
+        mode = "simdive"       # shipping a policy means approximate serving
+    if mode == "exact":
+        return cfg
+    return cfg.with_approx(ApproxConfig(
+        mode=mode, emulate=emulate, use_in_softmax=True, policy=policy,
+        backend=backend))
 
 
 def render_plan(plan, cfg) -> str:
@@ -244,6 +266,7 @@ def render_plan(plan, cfg) -> str:
 
 # --------------------------------------------------------------------- cli --
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -283,16 +306,8 @@ def main():
         print(f"# policy: {args.policy} ({len(policy.entries)} entries, "
               f"{len(policy.distinct_configs())} distinct dispatch "
               "config(s))")
-    cfg = get_config(args.arch, smoke=args.smoke)
-    mode = args.approx
-    if policy is not None and mode == "exact":
-        mode = "simdive"       # shipping a policy means approximate serving
-    if mode != "exact":
-        # big-model serving default: divider-softmax only (linears stay MXU
-        # int8); --emulate opts into bit-exact approximate linears
-        cfg = cfg.with_approx(ApproxConfig(
-            mode=mode, emulate=args.emulate, use_in_softmax=True,
-            policy=policy))
+    cfg = serving_config(get_config(args.arch, smoke=args.smoke),
+                         args.approx, emulate=args.emulate, policy=policy)
     plan = resolve_serving_plan(cfg)
     print(render_plan(plan, cfg))
 

@@ -29,6 +29,7 @@ from repro.configs.base import ShapeConfig
 from repro.core.approx import ApproxConfig
 from repro.data import SyntheticLM, make_source
 from repro.launch import sharding as shardlib
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.specs import (
     as_shardings,
@@ -98,7 +99,8 @@ def train(cfg, shape: ShapeConfig, *, steps: int, ckpt_dir: str | None,
           save_every: int = 50, resume: str = "auto", seed: int = 0,
           lr: float = 3e-4, tp: int = 1, log_every: int = 10,
           keep: int = 3, stop_after: int | None = None,
-          microbatch: int = 1, schedule=None, grad_compress: bool = False):
+          microbatch: int = 1, schedule=None, grad_compress: bool = False,
+          devices=None):
     """``stop_after``: simulate preemption — exit after that many steps
     WITHOUT the final checkpoint (only periodic commits survive), exactly
     like a killed worker. The lr schedule is always pinned to ``steps`` so
@@ -115,11 +117,17 @@ def train(cfg, shape: ShapeConfig, *, steps: int, ckpt_dir: str | None,
     ``grad_compress``: int8 error-feedback gradient compression; the
     residual tree joins the checkpoint so resume carries the feedback
     state too.
+
+    ``devices``: where to train (default: every device). More than one
+    shards the step over a ("data", "model") mesh with ``tp`` model
+    ways; one trains unsharded on that device.
     """
     lm = build(cfg)
     opt = adamw(cosine_schedule(lr, warmup=min(100, steps // 10 + 1),
                                 total=steps))
-    mesh = make_host_mesh(model=tp) if len(jax.devices()) > 1 else None
+    devices = jax.devices() if devices is None else list(devices)
+    mesh = make_host_mesh(model=tp, devices=devices) \
+        if len(devices) > 1 else None
     source = make_source(cfg, shape, seed=seed)
 
     key = jax.random.PRNGKey(seed)
@@ -161,6 +169,8 @@ def train(cfg, shape: ShapeConfig, *, steps: int, ckpt_dir: str | None,
             stack.enter_context(mesh)
             stack.enter_context(
                 shardlib.use_rules(mesh, {"batch": batch_axes_for(mesh)}))
+        else:
+            stack.enter_context(jax.default_device(devices[0]))
         if params is None:
             params = jax.jit(lm.init)(key)
             opt_state = jax.jit(opt.init)(params)
@@ -214,15 +224,8 @@ def train(cfg, shape: ShapeConfig, *, steps: int, ckpt_dir: str | None,
     return params, losses
 
 
-class _null_ctx:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -24,6 +24,7 @@ from repro.launch.serve import (
     merge_cache,
     quantize_params,
     resolve_serving_plan,
+    serving_config,
 )
 from repro.models import build
 from repro.models.layers import QuantizedWeight
@@ -132,6 +133,28 @@ def test_policy_roundtrip_into_serving_plan(tmp_path):
     assert all(row.source == "policy" for row in plan)
     att = next(r for r in plan if r.op == "attention")
     assert (att.width, att.coeff_bits, att.frac_out) == (16, 8, 15)
+
+
+@pytest.mark.parametrize("backend,want", [("auto", "ref"),
+                                          ("pallas", "pallas-interpret")])
+def test_serving_config_plan_names_the_resolved_backend(backend, want):
+    """The serve CLI's config resolves 'auto' (compiled kernels on a TPU,
+    the reference elsewhere — this host has no TPU); the printed plan
+    names the concrete lowering, never the unresolved request."""
+    cfg = serving_config(get_config(ARCH, smoke=True), "simdive",
+                         emulate=True, backend=backend)
+    plan = resolve_serving_plan(cfg)
+    assert cfg.approx.backend == backend
+    assert [r.backend for r in plan] == [want] * 3
+
+
+def test_serving_config_exact_and_policy_modes():
+    base = get_config(ARCH, smoke=True)
+    assert serving_config(base, "exact") is base
+    pol = _policy()
+    cfg = serving_config(base, "exact", policy=pol)     # policy => approx
+    assert cfg.approx.mode == "simdive" and cfg.approx.policy is pol
+    assert cfg.approx.backend == "auto"
 
 
 def test_policy_matching_defaults_token_parity():
@@ -456,3 +479,23 @@ def test_scheduler_self_heal_off_keeps_legacy_shape():
     stats = sched.run()
     assert stats["completed"] == 2
     assert stats["quarantines"] == 0 and stats["guard_trips"] == 0
+
+
+# ---------------------------------------------------------- compile cache --
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache sits at the checkout's fixed .jax_cache/."""
+    from repro.launch import compile_cache as cc
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cc.use_compile_cache() == str(cc.REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.REPO_CACHE_DIR)
+        assert cc.REPO_CACHE_DIR.name == ".jax_cache"
+        assert (cc.REPO_CACHE_DIR.parent / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
