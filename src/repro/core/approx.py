@@ -253,12 +253,18 @@ def _approx_matmul_fwd_impl(x, w, cfg):
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     spec, backend = cfg.resolve("matmul")
-    qx, sx, scx = quantize_sign_magnitude(x2, spec.width)
-    qw, sw, scw = quantize_sign_magnitude(w, spec.width, axis=0)
+    # named scopes put the device time of the dispatch around the kernel
+    # (operand quantize, output rescale) down to its own name in a trace;
+    # XLA names a fusion by its root, so the cast back to x.dtype, which
+    # the rescale fuses into, stays inside the scope
+    with jax.named_scope("approx.quantize"):
+        qx, sx, scx = quantize_sign_magnitude(x2, spec.width)
+        qw, sw, scw = quantize_sign_magnitude(w, spec.width, axis=0)
     mm = get_op("matmul_emul", spec, backend=backend, guard=cfg.guard)
     acc = mm(qx, sx, qw, sw, k_chunk=cfg.k_chunk)
-    out = acc.astype(jnp.float32) * (scx * scw)
-    return out.reshape(*lead, w.shape[1]).astype(x.dtype)
+    with jax.named_scope("approx.rescale"):
+        out = acc.astype(jnp.float32) * (scx * scw)
+        return out.reshape(*lead, w.shape[1]).astype(x.dtype)
 
 
 def _approx_matmul_fwd(x, w, cfg):

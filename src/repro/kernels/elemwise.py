@@ -76,5 +76,6 @@ def elemwise_pallas(a, b, spec: SimdiveSpec, op: str = "mul",
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        name="elemwise_pallas",
         interpret=interpret,
     )(a, b, mode)

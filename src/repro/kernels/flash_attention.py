@@ -301,6 +301,7 @@ def flash_attention_pallas(q, k, v, *, spec: SimdiveSpec = DEFAULT_DIV_SPEC,
             out_shape=jax.ShapeDtypeStruct((BH, Sq, dh), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
+            name="flash_attention_pallas",
             interpret=interpret,
         )(q, k, v)
     kern = functools.partial(_kernel, **common)
@@ -321,6 +322,7 @@ def flash_attention_pallas(q, k, v, *, spec: SimdiveSpec = DEFAULT_DIV_SPEC,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_pallas",
         interpret=interpret,
     )(q, k, v)
 

@@ -237,6 +237,7 @@ def logmatmul_pallas(x, w, spec: SimdiveSpec, blocks=DEFAULT_BLOCKS,
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
+            name="logmatmul_pallas",
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")
@@ -254,6 +255,7 @@ def logmatmul_pallas(x, w, spec: SimdiveSpec, blocks=DEFAULT_BLOCKS,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bk // u, bm, u), x.dtype)],
+        name="logmatmul_pallas",
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")

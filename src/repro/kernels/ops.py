@@ -185,8 +185,11 @@ def _matmul_emul_pallas(qx, sx, qw, sw, *, spec, block, interpret,
     log-domain kernel. Accumulates in int32 (exact for width 8 / bounded K;
     the int64 reference is the accuracy-study oracle)."""
     del k_chunk  # the kernel's K-tiling replaces the host-side chunking
-    x = qx.astype(jnp.int32) * sx
-    w = qw.astype(jnp.int32) * sw
+    # the operand quantize fuses into this recombination, which XLA then
+    # names it by: keep it under the quantize's scope
+    with jax.named_scope("approx.quantize"):
+        x = qx.astype(jnp.int32) * sx
+        w = qw.astype(jnp.int32) * sw
     return _matmul_int_pallas(x, w, spec=spec, block=block,
                               interpret=interpret).astype(jnp.int64)
 

@@ -100,5 +100,6 @@ def packed_pallas(aw, bw, spec: SimdiveSpec, op: str = "mul", mode=None,
         ],
         out_specs=pl.BlockSpec((bm, 2 * bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, 2 * Nw), jnp.uint32),
+        name="packed_pallas",
         interpret=interpret,
     )(aw, bw, mode)

@@ -44,6 +44,21 @@ the queue pinned to the recovery rung after an exponential backoff
 way. Everything is surfaced in ``stats()``: ``guard_trips``,
 ``quarantines``, ``retries``, ``timeouts``, ``failed``, plus per-token
 rung attribution (retried tokens count against ``'recovery'``).
+
+Profiling: every tick is a ``jax.profiler.TraceAnnotation`` span on the
+host, so a trace puts the device's idle time down to a scheduler phase:
+
+    sched.step (tick, kind)
+      sched.watchdog
+      sched.admit (n)          only on a tick that admits
+        sched.prefill          enqueue the batched prefill
+        sched.admit_sync       read the first tokens back
+        sched.rows_ok          read the finite-logit watch back
+        sched.insert           enqueue the cache insert
+      sched.decode
+        sched.decode_dispatch  upload tok/pos, enqueue the step
+        sched.decode_sync      read the argmax back
+        sched.rows_ok
 """
 from __future__ import annotations
 
@@ -57,6 +72,10 @@ import numpy as np
 from repro.core.approx import ApproxConfig
 from repro.kernels.registry import GuardTripped
 from repro.models import build
+
+#: a host span on the profiler's clock; without an active profiler it costs
+#: about a microsecond and never formats its keyword arguments
+_span = jax.profiler.TraceAnnotation
 
 __all__ = [
     "Request",
@@ -304,7 +323,8 @@ class Scheduler:
         garbage into someone's completion."""
         if not (self.self_heal and self.watch_logits):
             return np.ones(self.batch, bool)
-        return np.asarray(jnp.isfinite(logits).all(axis=-1))
+        with _span("sched.rows_ok"):
+            return np.asarray(jnp.isfinite(logits).all(axis=-1))
 
     def _bounce(self, req: Request, reason: str):
         """Discard a poisoned request's partial work and either requeue
@@ -382,6 +402,10 @@ class Scheduler:
         if not free or not self.queue:
             return
         take = min(len(free), len(self.queue))
+        with _span("sched.admit", n=take):
+            self._admit_into(free, take)
+
+    def _admit_into(self, free: list, take: int):
         reqs = [self.queue.popleft() for _ in range(take)]
         prompts = np.zeros((self.batch, self.prompt_len), np.int32)
         # padding rows scatter out of range -> dropped by the insert
@@ -392,9 +416,11 @@ class Scheduler:
         lvl = self._effective_level(reqs)
         lm = self.lms[lvl]
         try:
-            logits, pre = lm.prefill(self.params,
-                                     {"tokens": jnp.asarray(prompts)})
-            first = np.asarray(jnp.argmax(logits, -1)).astype(np.int32)
+            with _span("sched.prefill"):
+                logits, pre = lm.prefill(self.params,
+                                         {"tokens": jnp.asarray(prompts)})
+            with _span("sched.admit_sync"):
+                first = np.asarray(jnp.argmax(logits, -1)).astype(np.int32)
             rowok = self._rows_ok(logits)
         except GuardTripped as e:
             # eager guarded dispatch rejected the whole prefill batch
@@ -403,7 +429,8 @@ class Scheduler:
             for req in reqs:
                 self._bounce(req, f"guard: {e.reason}")
             return
-        self.cache = self._insert(self.cache, pre, jnp.asarray(slot_ix))
+        with _span("sched.insert"):
+            self.cache = self._insert(self.cache, pre, jnp.asarray(slot_ix))
         name = self.levels[lvl].name
         for j, req in enumerate(reqs):
             if not rowok[j]:
@@ -431,11 +458,16 @@ class Scheduler:
     def _decode(self):
         if not any(r is not None for r in self.slots):
             return
+        with _span("sched.decode"):
+            self._decode_slots()
+
+    def _decode_slots(self):
         lvl = self._effective_level()
         try:
-            logits, cache = self.steps[lvl](self.params, self.cache,
-                                            jnp.asarray(self.tok),
-                                            jnp.asarray(self.pos))
+            with _span("sched.decode_dispatch"):
+                logits, cache = self.steps[lvl](self.params, self.cache,
+                                                jnp.asarray(self.tok),
+                                                jnp.asarray(self.pos))
         except GuardTripped as e:
             self.counters["guard_trips"] += 1
             self.events.append((self.tick_no, "guard", str(e)))
@@ -444,7 +476,8 @@ class Scheduler:
                     self._quarantine(s, req, f"guard: {e.reason}")
             return
         self.cache = cache
-        nxt = np.asarray(jnp.argmax(logits, -1)).astype(np.int32)
+        with _span("sched.decode_sync"):
+            nxt = np.asarray(jnp.argmax(logits, -1)).astype(np.int32)
         rowok = self._rows_ok(logits)
         name = self.levels[lvl].name
         for s, req in enumerate(self.slots):
@@ -465,13 +498,22 @@ class Scheduler:
                 self._retire(s, req)
 
     def step(self):
-        """One scheduler tick: watchdog, adjust level, admit, decode."""
+        """One scheduler tick: watchdog, adjust level, admit, decode.
+
+        Each tick is a ``sched.step`` profiler span of kind ``admit`` (the
+        queue holds work and a slot stands free as the tick starts) or
+        ``decode``, with a child span per phase; see the module docstring.
+        """
         self.tick_no += 1
-        if self.self_heal:
-            self._watchdog()
-        self._adjust_level()
-        self._admit()
-        self._decode()
+        admitting = bool(self.queue) and any(r is None for r in self.slots)
+        with _span("sched.step", tick=self.tick_no,
+                   kind="admit" if admitting else "decode"):
+            if self.self_heal:
+                with _span("sched.watchdog"):
+                    self._watchdog()
+            self._adjust_level()
+            self._admit()
+            self._decode()
 
     def run(self, max_ticks: int = 10_000) -> dict:
         """Tick until every submitted request retires (or fails loudly

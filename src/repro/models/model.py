@@ -57,6 +57,7 @@ class LM:
         return params
 
     # -------------------------------------------------------------- embed --
+    @jax.named_scope("model.embed")
     def _embed(self, params, batch):
         cfg = self.cfg
         adt = _dt(cfg.dtype)
@@ -95,6 +96,7 @@ class LM:
             pos = jnp.broadcast_to(jnp.arange(S)[None] + offset, (B, S))
         return pos
 
+    @jax.named_scope("model.head")
     def _head(self, params, x):
         cfg = self.cfg
         if cfg.tie_embeddings:

@@ -242,6 +242,7 @@ def _approx_segments(cfg: ModelConfig):
                  for lo, hi, acfg in segs)
 
 
+@jax.named_scope("model.kv_cache")
 def _write_token(buf, i, slot, new):
     """Write one decoded token's (B,1,KV,dh) slab into the stacked
     (L,B,Smax,KV,dh) cache at layer ``i``, seq slot ``slot``.
@@ -517,7 +518,8 @@ def stack_decode(params, x, cfg: ModelConfig, cache, pos, positions):
             cgroup = jax.tree.map(lambda a: a[sl], cache["ssm"])
             x, c2 = jax.lax.scan(body, x, (group, cgroup), unroll=unroll)
             new_ssm_parts.append(c2)
-            kv = {"k": kc[g], "v": vc[g]}
+            with jax.named_scope("model.kv_cache"):
+                kv = {"k": kc[g], "v": vc[g]}
             x, (k_new, v_new) = _hybrid_shared(params, x, cfg, positions, g,
                                                cache=kv, pos=pos)
             kc = _write_token(kc, g, slot, k_new)
@@ -542,10 +544,13 @@ def stack_decode(params, x, cfg: ModelConfig, cache, pos, positions):
         def body(carry, pl_i):
             xc, kc, vc = carry
             pl, i = pl_i
-            layer_cache = {
-                "k": jax.lax.dynamic_index_in_dim(kc, i, 0, keepdims=False),
-                "v": jax.lax.dynamic_index_in_dim(vc, i, 0, keepdims=False),
-            }
+            with jax.named_scope("model.kv_cache"):
+                layer_cache = {
+                    "k": jax.lax.dynamic_index_in_dim(kc, i, 0,
+                                                      keepdims=False),
+                    "v": jax.lax.dynamic_index_in_dim(vc, i, 0,
+                                                      keepdims=False),
+                }
             y, (k_new, v_new) = attn_block_decode(pl, xc, seg_cfg,
                                                   layer_cache, pos, positions)
             kc = _write_token(kc, i, slot, k_new)
