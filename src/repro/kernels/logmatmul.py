@@ -4,8 +4,9 @@ C[m,n] = sum_k  sign * SIMDive(|X[m,k]|, |W[k,n]|)
 
 Two schedules over the same tile math (:func:`_chunk_sweep` — a
 ``fori_loop`` over ``k_unroll``-wide K chunks, each chunk one sign split +
-LOD/log pass and ``k_unroll`` rank-1 steps through the fused
-correct+anti-log stage :func:`datapath.log_mul`):
+LOD/log pass per operand, w's side of the coefficient lookup, and
+``k_unroll`` rank-1 steps through the ternary add + anti-log stage
+:func:`datapath.antilog_mul`):
 
 * ``pipeline_depth=0`` — grid (M/bm, N/bn, K/bk) with the K axis innermost
   ("arbitrary" semantics): Pallas streams the (bm, bk)/(bk, bn) operand
@@ -26,6 +27,17 @@ VMEM scratch by static slices once per tile. Every intermediate is 2-D
 trips for straight-line code per trip; it and ``pipeline_depth`` are
 autotuned axes: the registry's block candidates carry them as 4th/5th
 components (see ops.py).
+
+Coefficient lookup: the region index concatenates x's and w's top
+``index_bits`` fraction bits, and in a rank-1 step x's are constant along
+a product row and w's along a column. So the 2^ib x 2^ib table is split
+(:func:`_split_lookup`): once per chunk, on the (u, bn) w chunk
+(:func:`_w_stage`), a select tree over w's bits gives 2^ib leaf arrays,
+one per region of x; per step a tree of depth ``index_bits`` over x's
+bits picks among the leaves' step-k rows — at most 2^ib - 1 selects on
+the (bm, bn) tile, where the 64-leaf tree of :func:`datapath.log_mul`
+costs up to 63. The table is a trace-time constant; equal subtrees
+collapse, and an all-zero table (coeff_bits 0) costs no select.
 
 VMEM per step: the (bm, bk) x tile and (bk/u, u, bn) w tile per pipeline
 slot, the chunk-major x staging tile (its u-wide rows pad to 128 lanes),
@@ -49,9 +61,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.mitchell import frac_bits
 from repro.core.simdive import SimdiveSpec
 from . import datapath as dp
 
@@ -65,31 +79,117 @@ K_UNROLL_CANDIDATES = (1, 4, 8, 16)
 PIPELINE_CANDIDATES = (0, 2, 4)
 
 
-def _chunk_partial(xc, wc, tab, *, spec: SimdiveSpec):
-    """int32 partial product-sum of one K chunk: (bm, u) x (u, bn).
+def _region_masks(l, width: int, index_bits: int):
+    """Bit b of each log value's region (the top ``index_bits`` of its
+    F-bit fraction, :func:`repro.core.error_lut.region_index`) as a mask,
+    b = 0 first: bit ``F - index_bits + b`` of ``l`` itself."""
+    sh = frac_bits(width) - index_bits
+    return [(l & np.asarray(1 << (sh + b), l.dtype)) != 0
+            for b in range(index_bits)]
 
-    Sign split and LOD/log run once on the chunk's operands; then each of
-    the u rank-1 steps pushes a (bm, 1) column against a (1, bn) row
-    through the fused correct+anti-log stage (:func:`datapath.log_mul`),
-    broadcasting to a (bm, bn) tile — 2-D values only, which is what
-    Mosaic lays out natively. A zero operand is folded into the sign (a
-    zero sign drops the product), so no zero mask is broadcast. Both
-    kernel schedules and :func:`_tile_partial` run this one function, so
-    bit-identity between them is structural.
+
+def _select_tree(bits, leaves, keys):
+    """``leaves[i]`` for the index whose bit b is ``bits[b]``, as a binary
+    select tree. ``keys[i]`` names what ``leaves[i]`` holds (host-side):
+    a subtree whose keys are all equal collapses to its first leaf, so an
+    all-equal range costs no select."""
+    def node(lo: int, size: int, b: int):
+        if all(keys[i] == keys[lo] for i in range(lo, lo + size)):
+            return leaves[lo]
+        half = size // 2
+        return jnp.where(bits[b], node(lo + half, half, b - 1),
+                         node(lo, half, b - 1))
+
+    return node(0, len(leaves), len(bits) - 1)
+
+
+def _table_grid(tab, index_bits: int) -> np.ndarray:
+    """The coefficient table as host int32 constants indexed [rx, rw]:
+    ``tab[(rx << index_bits) | rw]``."""
+    n = 1 << index_bits
+    return np.asarray(tab).astype(np.int32).reshape(n, n)
+
+
+def _w_stage(wc, tab, *, spec: SimdiveSpec):
+    """The w side of one (u, bn) K chunk: log values, signs (a zero
+    operand folded into a zero sign) and the split table's leaves.
+
+    Leaf r is ``tab[(r << index_bits) | rw]`` for w's region bits ``rw``:
+    a select tree over rw's bits with the table's entries as constants,
+    built once per chunk on (u, bn) values. An all-equal table
+    (coeff_bits 0) has no leaves."""
+    width, ib = spec.width, spec.index_bits
+    wm, sw = dp.sign_split(wc, width)
+    lw = dp.lod_log(wm, width, in_kernel=True)
+    sw = jnp.where(wm == 0, jnp.int32(0), sw)
+    grid = _table_grid(tab, ib)
+    if (grid == grid[0, 0]).all():
+        return lw, sw, ()
+    rw_bits = _region_masks(lw, width, ib)
+    leaves = tuple(
+        jnp.broadcast_to(_select_tree(rw_bits, list(row), list(row)),
+                         lw.shape)
+        for row in grid)
+    return lw, sw, leaves
+
+
+def _split_lookup(lx, leaves, tab, shape, *, width: int, index_bits: int):
+    """Step k's (bm, bn) coefficient tile, ``coeff(k)``, of the lookup
+    :func:`datapath.log_mul` does with a 2^(2 ib)-leaf tree per product.
+
+    x's region bits ``rx`` are constant along a product row and w's along
+    a column, so the table splits: :func:`_w_stage` builds one leaf per
+    x region on the w chunk, and each step picks among the leaves' step-k
+    rows (broadcast over sublanes) with a select tree of depth
+    ``index_bits`` over rx's bits: at most 2^ib - 1 selects on the
+    product tile. rx's bits are tested on the lane-broadcast x log column
+    that the anti-log adds anyway, not broadcast as masks. Equal table
+    rows collapse. Leaves on x would lane-broadcast 2^ib columns a step,
+    which Mosaic does with permutes: slower than the 64-leaf tree on a
+    v5e, at bm = 8 and at bm = 128.
+    """
+    grid = _table_grid(tab, index_bits)
+    if not leaves:
+        const = jnp.broadcast_to(grid[0, 0], shape)
+        return lambda k: const
+    keys = [tuple(row) for row in grid]
+
+    def coeff(k: int):
+        rows = [v[k:k + 1, :] for v in leaves]
+        lxk = jnp.broadcast_to(lx[:, k:k + 1], shape)
+        rx_bits = _region_masks(lxk, width, index_bits)
+        return jnp.broadcast_to(_select_tree(rx_bits, rows, keys), shape)
+
+    return coeff
+
+
+def _chunk_partial(xc, wst, tab, *, spec: SimdiveSpec):
+    """int32 partial product-sum of one K chunk: (bm, u) x (u, bn), the
+    w side ``wst`` given by :func:`_w_stage`.
+
+    Sign split and LOD/log run once on the x chunk; then each of the u
+    rank-1 steps picks its coefficient tile (:func:`_split_lookup`) and
+    pushes a (bm, 1) column against a (1, bn) row through the ternary add
+    and anti-log (:func:`datapath.antilog_mul`), broadcasting to a
+    (bm, bn) tile — 2-D values only, which is what Mosaic lays out
+    natively. A zero operand is folded into the sign (a zero sign drops
+    the product), so no zero mask is broadcast. Both kernel schedules and
+    :func:`_tile_partial` run this one function, so bit-identity between
+    them is structural.
     """
     width = spec.width
+    lw, sw, leaves = wst
     xm, sx = dp.sign_split(xc, width)               # (bm, u) magnitudes
-    wm, sw = dp.sign_split(wc, width)               # (u, bn)
     lx = dp.lod_log(xm, width, in_kernel=True)
-    lw = dp.lod_log(wm, width, in_kernel=True)
-    zero = jnp.int32(0)
-    sx = jnp.where(xm == 0, zero, sx)
-    sw = jnp.where(wm == 0, zero, sw)
-    acc = jnp.zeros((xc.shape[0], wc.shape[1]), jnp.int32)
+    sx = jnp.where(xm == 0, jnp.int32(0), sx)
+    shape = (xc.shape[0], lw.shape[1])
+    coeff = _split_lookup(lx, leaves, tab, shape, width=width,
+                          index_bits=spec.index_bits)
+    acc = jnp.zeros(shape, jnp.int32)
     for k in range(xc.shape[1]):
-        p = dp.log_mul(lx[:, k:k + 1], lw[k:k + 1, :], tab, width,
-                       spec.index_bits, round_out=spec.round_output,
-                       in_kernel=True)              # (bm, bn)
+        p = dp.antilog_mul(lx[:, k:k + 1], lw[k:k + 1, :], width,
+                           corr=coeff(k), round_out=spec.round_output,
+                           in_kernel=True)          # (bm, bn)
         acc = acc + dp.sign_join(p, sx[:, k:k + 1] * sw[k:k + 1, :])
     return acc
 
@@ -101,8 +201,9 @@ def _tile_partial(x_tile, w_tile, tab, *, spec: SimdiveSpec, bk: int,
     on plain values (the static analyzer proves the accumulator on it)."""
     acc = jnp.zeros((x_tile.shape[0], w_tile.shape[1]), jnp.int32)
     for k0 in range(0, bk, k_unroll):
-        acc = acc + _chunk_partial(x_tile[:, k0:k0 + k_unroll],
-                                   w_tile[k0:k0 + k_unroll], tab, spec=spec)
+        wst = _w_stage(w_tile[k0:k0 + k_unroll], tab, spec=spec)
+        acc = acc + _chunk_partial(x_tile[:, k0:k0 + k_unroll], wst, tab,
+                                   spec=spec)
     return acc
 
 
@@ -117,13 +218,25 @@ def _stage_chunks(x_tile, xs_ref, u: int):
 
 def _chunk_sweep(xs_ref, w_chunks, tab, *, spec: SimdiveSpec):
     """Sum :func:`_chunk_partial` over the chunks of one tile pair:
-    ``xs_ref``: (nc, bm, u), ``w_chunks``: (nc, u, bn)."""
-    def body(c, acc):
-        return acc + _chunk_partial(xs_ref[c], w_chunks[c], tab, spec=spec)
+    ``xs_ref``: (nc, bm, u), ``w_chunks``: (nc, u, bn).
+
+    Step c computes chunk c+1's :func:`_w_stage` and carries it (the last
+    step recomputes chunk nc-1's, unused): a loop-carried value is one
+    materialized array per chunk under the Pallas interpreter, whose XLA
+    would otherwise fuse the leaves into the product tile and recompute
+    them for every product (several times slower on the CPU); on the chip
+    it is work independent of the chunk's products."""
+    nc = xs_ref.shape[0]
+
+    def body(c, carry):
+        acc, wst = carry
+        nxt = _w_stage(w_chunks[jnp.minimum(c + 1, nc - 1)], tab, spec=spec)
+        return acc + _chunk_partial(xs_ref[c], wst, tab, spec=spec), nxt
 
     shape = (xs_ref.shape[1], w_chunks.shape[2])
-    return jax.lax.fori_loop(dp.I0, jnp.int32(xs_ref.shape[0]), body,
-                             jnp.zeros(shape, jnp.int32))
+    init = (jnp.zeros(shape, jnp.int32),
+            _w_stage(w_chunks[0], tab, spec=spec))
+    return jax.lax.fori_loop(dp.I0, jnp.int32(nc), body, init)[0]
 
 
 def _kernel(x_ref, w_ref, o_ref, xs_ref, *, tab, spec: SimdiveSpec, u: int):

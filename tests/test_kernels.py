@@ -3,11 +3,16 @@
 Everything is integer arithmetic — assertions are bit-for-bit equality.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import SimdiveSpec, pack
 from repro.kernels import simdive_elemwise, simdive_matmul_int, simdive_packed
+from repro.kernels import datapath as dp
+from repro.kernels.logmatmul import (_chunk_partial, _split_lookup,
+                                     _w_stage, logmatmul_pallas)
+from repro.kernels.ref import logmatmul_ref
 
 RNG = np.random.default_rng(7)
 
@@ -99,6 +104,91 @@ def test_logmatmul_pipeline_bit_identity(k_unroll, depth):
     want = simdive_matmul_int(x, w, spec, backend="ref")
     assert np.array_equal(np.asarray(base), np.asarray(want))
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("coeff_bits", [6, 0])
+@pytest.mark.parametrize("depth", [0, 2])
+@pytest.mark.parametrize("k_unroll", [1, 8])
+def test_logmatmul_every_w8_pair(coeff_bits, depth, k_unroll):
+    """Every signed w8 operand pair, zero included, through the kernel:
+    each x column and each w row holds all of [-255, 255] (in a rotated
+    order per k), so every product of every rank-1 step position meets
+    every pair of region bits; the int32 sums must equal ref.py's."""
+    spec = SimdiveSpec(width=8, coeff_bits=coeff_bits)
+    vals = np.concatenate([np.arange(-255, 256), [0]]).astype(np.int32)
+    x = np.stack([np.roll(vals, 61 * k) for k in range(8)], axis=1)
+    w = np.stack([np.roll(vals, 37 * k) for k in range(8)], axis=0)
+    x, w = jnp.asarray(x), jnp.asarray(w)                # (512, 8), (8, 512)
+    got = logmatmul_pallas(x, w, spec, blocks=(256, 256, 8),
+                           k_unroll=k_unroll, pipeline_depth=depth)
+    want = logmatmul_ref(x, w, spec)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("bm", [8, 128])
+def test_split_lookup_is_the_table(bm):
+    """The split lookup returns ``tab[(rx << ib) | rw]`` for every product
+    of every step, on an asymmetric table (the mul table is symmetric, so
+    a transposed split would pass the parity tests unseen)."""
+    u, bn = 8, 128
+    spec = SimdiveSpec(width=8, coeff_bits=6)
+    ib = spec.index_bits
+    rng = np.random.default_rng(bm)
+    tab = rng.integers(-40, 40, size=1 << 2 * ib).astype(np.int32)
+    xc = rng.integers(-255, 256, size=(bm, u)).astype(np.int32)
+    wc = rng.integers(-255, 256, size=(u, bn)).astype(np.int32)
+    lw, _, leaves = _w_stage(jnp.asarray(wc), tab, spec=spec)
+    lx = dp.lod_log(jnp.asarray(np.abs(xc), jnp.uint32), 8, in_kernel=True)
+    coeff = _split_lookup(lx, leaves, tab, (bm, bn), width=8, index_bits=ib)
+    rx = (np.asarray(lx) & 127) >> 4            # F = 7 fraction bits
+    rw = (np.asarray(lw) & 127) >> 4
+    for k in range(u):
+        want = tab[(rx[:, k:k + 1] << ib) | rw[k:k + 1, :]]
+        assert np.array_equal(np.asarray(coeff(k)), want)
+
+
+def _tile_selects(fn, shape, *args):
+    """``select_n`` equations of ``fn``'s jaxpr (nested jaxprs included)
+    whose output has the product tile's ``shape``."""
+    def count(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += count(sub)
+            n += (eqn.primitive.name == "select_n"
+                  and eqn.outvars[0].aval.shape == shape)
+        return n
+
+    return count(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("coeff_bits,lookup_per_step", [(6, 7), (0, 0)])
+def test_logmatmul_lookup_selects_per_step(coeff_bits, lookup_per_step):
+    """The region-coefficient lookup costs at most 2^index_bits - 1 = 7
+    selects per rank-1 step on the (bm, bn) product tile (the 64-leaf
+    tree cost 62), none for the all-zero table; the rest of the step's
+    selects are the anti-log's and the sign join's, counted on their own."""
+    spec = SimdiveSpec(width=8, coeff_bits=coeff_bits)
+    bm, u, bn = 16, 8, 256                  # three distinct 2-D shapes
+    tile = (bm, bn)
+    tab = dp.op_table_host("mul", 8, coeff_bits, spec.index_bits)
+    lx = jnp.zeros((bm, 1), jnp.uint32)
+    lw = jnp.zeros((1, bn), jnp.uint32)
+
+    def step(lx, lw, corr, sign):
+        p = dp.antilog_mul(lx, lw, 8, corr=corr, round_out=spec.round_output,
+                           in_kernel=True)
+        return dp.sign_join(p, sign)
+
+    rest = _tile_selects(step, tile, lx, lw, jnp.zeros(tile, jnp.int32),
+                         jnp.zeros(tile, jnp.int32))
+    chunk = _tile_selects(
+        lambda x, w: _chunk_partial(x, _w_stage(w, tab, spec=spec), tab,
+                                    spec=spec),
+        tile, jnp.zeros((bm, u), jnp.int32), jnp.zeros((u, bn), jnp.int32))
+    lookup = chunk - u * rest
+    assert lookup <= u * lookup_per_step
+    assert (lookup > 0) == (coeff_bits > 0)
 
 
 def test_logmatmul_close_to_exact():

@@ -72,13 +72,18 @@ def test_packed_w8_compiles(one_chip):
     _assert_kernel(one_chip, fn, words, words)
 
 
-@pytest.mark.parametrize("depth", [0, 2])
-def test_logmatmul_compiles(one_chip, depth):
+@pytest.mark.parametrize("m,depth", [
+    pytest.param(512, 0, id="0"),
+    pytest.param(512, 2, id="2"),
+    pytest.param(8, 0, id="decode-0"),     # the decode cell's bm = 8 tile
+    pytest.param(8, 2, id="decode-2"),
+])
+def test_logmatmul_compiles(one_chip, m, depth):
     def fn(x, w):
         return get_op("matmul_int", W8, "pallas-tpu",
                       block=(128, 128, 128, 8, depth))(x, w)
 
-    _assert_kernel(one_chip, fn, ((512, 960), jnp.int32),
+    _assert_kernel(one_chip, fn, ((m, 960), jnp.int32),
                    ((960, 2560), jnp.int32))
 
 
